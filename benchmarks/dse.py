@@ -16,6 +16,7 @@ number); on a TPU backend the same sweep is the real DSE.
 from __future__ import annotations
 
 from repro.core.complexity import dse_model
+from repro.device import enable_compile_cache
 from repro.core.tdc import DeconvDims
 from repro.kernels.autotune import (
     EngineConfig, autotune_deconv, epilogue_candidates, small_candidates,
@@ -90,6 +91,7 @@ def engine_block_sweep(
 
 
 def main():
+    enable_compile_cache()
     f = sweep(FPGA)
     b = best(f)
     print(f"dse,fpga,best_t_m={b['t_m']},best_t_n={b['t_n']},roof_gops={b['roof_gops']:.1f}"

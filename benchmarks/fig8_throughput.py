@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.gan_zoo import GANS
+from repro.device import enable_compile_cache
 from repro.core import tdc_deconv2d, winograd_deconv2d, zero_padded_deconv2d
 from repro.core.complexity import dse_model, mults_tdc, mults_winograd, mults_zero_padded
 from repro.models import gan as G
@@ -345,6 +346,7 @@ def chaos_test(*, archs=SMOKE_ARCHS, fault_rate: float = 0.1,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="smoke-scaled load test (shrunk channels, ~2s/pattern)")

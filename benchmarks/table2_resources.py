@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.gan_zoo import DCGAN
+from repro.device import enable_compile_cache
 from repro.core import decompose_weights, transform_weights
 from repro.core.tdc import DeconvDims
 
@@ -43,6 +44,7 @@ def run() -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     for r in run():
         print(
             f"table2,{r['model']},w_tdc_B={r['weight_bytes_tdc']},"

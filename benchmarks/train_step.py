@@ -93,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tdc import DeconvDims
+from repro.device import enable_compile_cache, interpret_mode
 from repro.kernels.autotune import EngineConfig, make_timed_fn, time_one
 
 from .workloads import GAN_LAYERS
@@ -577,8 +578,10 @@ def bench_sharded(
 
     avail = len(jax.devices())
     if avail < requested:
-        print(f"train_step,sharded,WARNING,only {avail} of {requested} "
-              "devices available (XLA flag not set before jax init?)")
+        raise RuntimeError(
+            f"--devices {requested} asked for, but JAX has {avail} "
+            f"{jax.default_backend()} device(s)"
+        )
     counts, d = [], 1
     while d <= min(requested, avail):
         counts.append(d)
@@ -652,8 +655,10 @@ def bench_weak_scaling(
 
     avail = len(jax.devices())
     if avail < requested:
-        print(f"train_step,weak_scaling,WARNING,only {avail} of {requested} "
-              "devices available (XLA flag not set before jax init?)")
+        raise RuntimeError(
+            f"--devices {requested} asked for, but JAX has {avail} "
+            f"{jax.default_backend()} device(s)"
+        )
     counts, d = [], 1
     while d <= min(requested, avail):
         counts.append(d)
@@ -867,8 +872,9 @@ def main(argv: list[str] | None = None) -> dict:
     args = ap.parse_args(argv)
     if args.devices_only and not args.devices:
         ap.error("--devices-only requires --devices N")
+    enable_compile_cache()
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     archs = [] if args.devices_only else (
         [args.arch] if args.arch else sorted(GAN_LAYERS)
     )

@@ -13,7 +13,10 @@ from repro.core import (
     zero_padded_deconv2d,
 )
 from repro.core.complexity import LayerShape, mults_tdc, mults_winograd, mults_zero_padded
+from repro.device import enable_compile_cache
 from repro.kernels.ops import winograd_deconv2d_fused
+
+enable_compile_cache()
 
 # DCGAN layer 2: 8x8x512 -> 16x16x256, K_D=5, S=2 (Table I row 1)
 dims = DeconvDims(kernel=5, stride=2, padding=2, output_padding=1)

@@ -9,11 +9,13 @@ import jax
 import jax.numpy as jnp
 
 from repro import data as D
+from repro.device import enable_compile_cache
 from repro.configs import LMS, smoke_config
 from repro.models import lm
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-12b", choices=sorted(LMS))
     ap.add_argument("--batch", type=int, default=4)

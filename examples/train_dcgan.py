@@ -4,15 +4,19 @@ Winograd engine pipeline on synthetic data, with checkpointing.
 Default impls are the current fastest path — chained engine generator AND
 chained Winograd-Conv discriminator, so the quickstart's full adversarial
 train step (both nets, both grads) runs in the engine domain.  Default
-model is a width-reduced DCGAN that trains a few hundred steps in CPU
-minutes; --full uses the exact 1024-512-256-128 generator (~12.7M params).
+model is a width-reduced DCGAN; --full uses the exact 1024-512-256-128
+generator (~12.7M params).  The default impls need a TPU; on a CPU host
+name the interpret twins explicitly.
 
 Run:  PYTHONPATH=src python examples/train_dcgan.py --steps 200
+      PYTHONPATH=src JAX_PLATFORMS=cpu python examples/train_dcgan.py \
+          --impl pallas_chained_interpret --disc-impl pallas_chained_interpret
 """
 import argparse
 import dataclasses
 
 from repro.configs.gan_zoo import DCGAN
+from repro.device import enable_compile_cache, require_tpu
 from repro.train.trainer import train_gan
 
 
@@ -40,13 +44,14 @@ def main():
                              "pallas_chained", "pallas_chained_interpret"])
     args = ap.parse_args()
 
-    # "auto" picks the engine-chained pipeline (generator AND discriminator
-    # fully in the engine domain), in interpret mode off-TPU
-    import jax
-
-    suffix = "" if jax.default_backend() == "tpu" else "_interpret"
-    impl = f"pallas_chained{suffix}" if args.impl == "auto" else args.impl
-    disc_impl = f"pallas_chained{suffix}" if args.disc_impl == "auto" else args.disc_impl
+    # "auto" is the engine-chained pipeline (generator AND discriminator
+    # fully in the engine domain) compiled for the TPU; off the TPU name an
+    # *_interpret impl explicitly
+    if "auto" in (args.impl, args.disc_impl):
+        require_tpu("--impl/--disc-impl auto (pallas_chained)")
+    enable_compile_cache()
+    impl = "pallas_chained" if args.impl == "auto" else args.impl
+    disc_impl = "pallas_chained" if args.disc_impl == "auto" else args.disc_impl
 
     cfg = DCGAN
     if not args.full:

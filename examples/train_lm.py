@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import data as D
+from repro.device import enable_compile_cache
 from repro.configs import LMS, smoke_config
 from repro.models import lm
 from repro.optim import adamw_init, adamw_update
@@ -17,6 +18,7 @@ from repro.train import checkpoint as C
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b", choices=sorted(LMS))
     ap.add_argument("--steps", type=int, default=50)

@@ -1,32 +1,16 @@
-"""Single home for every JAX API that drifted across versions.
+"""The JAX spellings the rest of the codebase imports from one place.
 
-The repo supports jax 0.4.3x through 0.6+.  Anything version-sensitive is
-imported from here so a JAX upgrade (or downgrade) is a one-file audit:
-
-  =====================  ==========================  =========================
-  symbol                 jax <= 0.4.x                jax >= 0.5 / 0.6
-  =====================  ==========================  =========================
-  tpu_compiler_params    pltpu.TPUCompilerParams     pltpu.CompilerParams
-  make_mesh              jax.make_mesh(shape, axes)  + axis_types=(Auto,)*k
-  shard_map              jax.experimental.shard_map  jax.shard_map
-                         (check_rep=...)             (check_vma=...)
-  tree_*                 jax.tree_util.tree_*        jax.tree.* (alias kept)
-  =====================  ==========================  =========================
-
-Rule for the rest of the codebase: ``from repro.compat import ...`` — never
-touch ``pltpu.*CompilerParams``, ``jax.sharding.AxisType``, or bare
-``shard_map`` directly.
+Written for the installed jax 0.9.0: ``pltpu.CompilerParams``,
+``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map(check_vma=)`` and
+``jax.tree.*``.  Importers use ``from repro.compat import ...``, so a JAX
+upgrade that renames one of these is a one-file change.
 """
 from __future__ import annotations
-
-import functools
-from typing import Any, Callable
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "JAX_VERSION",
     "tpu_compiler_params",
     "make_mesh",
     "shard_map",
@@ -36,82 +20,16 @@ __all__ = [
     "tree_unflatten",
 ]
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
-
-
-# --------------------------------------------------------------------------
-# Pallas TPU compiler params: renamed TPUCompilerParams -> CompilerParams in
-# jax 0.5; the old name was removed later still.  Keyword surface is the same
-# for the subset we use (dimension_semantics).
-# --------------------------------------------------------------------------
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
-def tpu_compiler_params(**kwargs: Any):
-    """Construct Mosaic compiler params under either name."""
-    return _CompilerParams(**kwargs)
-
-
-# --------------------------------------------------------------------------
-# Mesh construction: jax.sharding.AxisType and the axis_types= kwarg of
-# jax.make_mesh only exist from jax 0.5.  On older versions every axis is
-# implicitly Auto, which is exactly what we request on new versions — so
-# dropping the kwarg is semantics-preserving.
-# --------------------------------------------------------------------------
-AxisType = getattr(jax.sharding, "AxisType", None)
+tpu_compiler_params = pltpu.CompilerParams
+shard_map = jax.shard_map
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh with every axis Auto, on any supported jax version."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-# --------------------------------------------------------------------------
-# shard_map: moved from jax.experimental.shard_map to jax.shard_map, and the
-# replication-check kwarg was renamed check_rep -> check_vma.  We accept the
-# new-style spelling (check_vma=) and translate for old versions.
-# --------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KWARG = "check_vma"
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KWARG = "check_rep"
-
-
-def shard_map(f: Callable | None = None, /, **kwargs: Any):
-    """Version-portable jax.shard_map.
-
-    Callable both ways: ``shard_map(f, mesh=..., ...)`` and as a partial
-    ``shard_map(mesh=..., ...)(f)``.  Use ``check_vma=`` (the modern name);
-    it is translated to ``check_rep=`` on jax 0.4.x.
-    """
-    if "check_vma" in kwargs and _CHECK_KWARG != "check_vma":
-        kwargs[_CHECK_KWARG] = kwargs.pop("check_vma")
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    return _shard_map(f, **kwargs)
-
-
-# --------------------------------------------------------------------------
-# Tree utilities: jax.tree.* is the modern spelling (present since 0.4.25);
-# fall back to jax.tree_util for anything older, and keep tree_util-only
-# helpers reachable through one import site.
-# --------------------------------------------------------------------------
-if hasattr(jax, "tree"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-else:  # pragma: no cover - ancient jax
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
+    """jax.make_mesh with every axis Auto (GSPMD-propagated)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tdc import DeconvDims
+from repro.device import interpret_mode
 from repro.optim import adamw_init, adamw_update
 
 from . import ops
@@ -533,7 +534,7 @@ def autotune_conv1d(
     if mode not in ("fwd", "grad", "step"):
         raise ValueError(mode)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if candidates is None:
         candidates = conv1d_candidates()
     B, L, N = input_shape
@@ -575,7 +576,7 @@ def autotune_conv(
     if mode not in ("fwd", "grad", "step"):
         raise ValueError(mode)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if candidates is None:
         candidates = conv_candidates()
     B, H, W, N = input_shape
@@ -637,7 +638,7 @@ def autotune_deconv(
     if grad_compression is not None and (mode != "step" or mesh is None):
         raise ValueError("grad_compression requires mode='step' and a mesh")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if candidates is None:
         candidates = candidate_configs()
     B, H, W, N = input_shape

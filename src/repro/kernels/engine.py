@@ -109,6 +109,40 @@ def _apply_epilogue(y, scale, bias, activation: str):
     return y
 
 
+# Scoped-VMEM budget.  Mosaic's default scoped limit (16 MiB on v5e) is
+# below what the unrolled compiled bodies need at the published GAN widths
+# (the conv bwd_w at the discriminator's 4-phase cells wants ~16.6 MiB).
+# The pipeline holds two buffers of every in/out block plus the scratch, and
+# the body's unrolled temporaries (stitched tiles, transformed xw, per
+# position partials) are of the same order again, so each call asks for
+# twice its pipeline footprint — never below the default, and well inside
+# the v5e's 128 MiB of physical VMEM.
+_VMEM_MIN_BYTES = 16 * 2**20
+_VMEM_MAX_BYTES = 100 * 2**20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM footprint of one block: the last two dims occupy whole
+    (sublane, 128-lane) tiles — 8 sublanes for 32-bit, more when packed."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = (1, 1, *shape)
+    sublanes = 8 * max(1, 4 // itemsize)
+    return int(np.prod(lead)) * _rup(rows, sublanes) * _rup(cols, 128) * itemsize
+
+
+def _compiler_params(blocks, scratch):
+    """Mosaic params for the engines' (parallel, parallel, arbitrary) grids,
+    with a scoped-VMEM limit sized from ``blocks`` (every pipelined in/out
+    block as (shape, dtype)) and ``scratch`` ((shape, dtype) of the VMEM
+    accumulator)."""
+    pipeline = 2 * sum(_vmem_bytes(s, d) for s, d in blocks) + _vmem_bytes(*scratch)
+    limit = min(max(2 * pipeline, _VMEM_MIN_BYTES), _VMEM_MAX_BYTES)
+    return tpu_compiler_params(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit,
+    )
+
+
 def _const_operand(bt_mat, pos_idx):
     """Pack the static B^T matrix and packed-position indices into one tiny
     fp32 operand: Pallas kernels cannot capture array constants (even in
@@ -397,8 +431,10 @@ def domain_engine(
         out_specs=pl.BlockSpec((bt, S2 * m2, bm), lambda i, j, k: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Tp, S2 * m2, Mp), xw.dtype),
         scratch_shapes=[pltpu.VMEM((C, bt, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bt, n2, bn), xw.dtype), ((C, bn, bm), ww_packed.dtype),
+             ((bt, S2 * m2, bm), xw.dtype)],
+            ((C, bt, bm), jnp.float32),
         ),
         interpret=interpret,
     )(xw_p, ww_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
@@ -726,11 +762,23 @@ def fused_engine(
         pl.BlockSpec((n + C, n), lambda i, j, k: (0, 0)),
     ]
     const_op = jnp.asarray(_const_operand(bt_mat, pos_idx))
+    # the out blocks of every output mode, counted together as a bound on the
+    # one this call builds: the scratch rows (S2*m2 per tile), the nhwc
+    # interleave, and the cells layout plus its crop-window mask block
+    sm = m * max(stride, 1)
+    out_blocks = [
+        ((bty * tx, S2 * m2, bm), cells.dtype),
+        ((bty * sm, tx * sm, bm), cells.dtype),
+        ((bty * stride, tx * stride, m * m, bm), cells.dtype),
+        ((bty * stride, tx * stride, m * m, 1), jnp.float32),
+    ]
     common = dict(
         grid=grid,
         scratch_shapes=[pltpu.VMEM((C, bty * tx, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [(cell_block, cells.dtype), ((1, h, Gxp, m2c, bn), cells.dtype),
+             ((C, bn, bm), ww_packed.dtype), *out_blocks],
+            ((C, bty * tx, bm), jnp.float32),
         ),
         interpret=interpret,
     )
@@ -1012,8 +1060,10 @@ def domain_engine_bwd_x(
         out_specs=pl.BlockSpec((bt, n2, bn), lambda i, j, k: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Tp, n2, Np), g.dtype),
         scratch_shapes=[pltpu.VMEM((bt, n2, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bt, s2m2, bm), g.dtype), ((C, bn, bm), ww_packed.dtype),
+             ((bt, n2, bn), g.dtype)],
+            ((bt, n2, bn), jnp.float32),
         ),
         interpret=interpret,
     )(g_p, ww_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
@@ -1101,8 +1151,10 @@ def domain_engine_bwd_w(
         out_specs=pl.BlockSpec((C, bn, bm), lambda i, j, k: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, Np, Mp), g.dtype),
         scratch_shapes=[pltpu.VMEM((C, bn, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bt, n2, bn), xw.dtype), ((bt, s2m2, bm), g.dtype),
+             ((C, bn, bm), g.dtype)],
+            ((C, bn, bm), jnp.float32),
         ),
         interpret=interpret,
     )(xw_p, g_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
@@ -1321,8 +1373,10 @@ def fused_engine_bwd_x(
         ),
         out_shape=jax.ShapeDtypeStruct((B, nob * bty, gx, m2c, Np), g.dtype),
         scratch_shapes=[pltpu.VMEM(((h + bty) * tx, phases * n * n, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bty, tx, s2m2, bm), g.dtype), ((h, tx, s2m2, bm), g.dtype),
+             ((C, bn, bm), ww_packed.dtype), ((bty, gx, m2c, bn), g.dtype)],
+            (((h + bty) * tx, phases * n * n, bn), jnp.float32),
         ),
         interpret=interpret,
     )(g_p, g_p, ww_p, inv_packed, jnp.asarray(_const_operand(bt_mat, pos_idx)))
@@ -1459,8 +1513,10 @@ def fused_engine_bwd_w(
         out_specs=pl.BlockSpec((C, bn, bm), lambda i, j, k: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, Np, Mp), g.dtype),
         scratch_shapes=[pltpu.VMEM((C, bn, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bty, Gxp, m2c, bn), cells.dtype), ((h, Gxp, m2c, bn), cells.dtype),
+             ((bty, tx, s2m2, bm), g.dtype), ((C, bn, bm), g.dtype)],
+            ((C, bn, bm), jnp.float32),
         ),
         interpret=interpret,
     )(cells_p, cells_p, g_p, inv_packed,
@@ -1680,8 +1736,11 @@ def winograd_conv1d_fused_engine(
     common = dict(
         grid=grid,
         scratch_shapes=[pltpu.VMEM((C, bty, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=_compiler_params(
+            [((bty, pm, bn), cells.dtype), ((h, pm, bn), cells.dtype),
+             ((C, bn, bm), ww_packed.dtype), ((bty, S2 * m, bm), cells.dtype),
+             ((bty * m * stride, bm), cells.dtype)],
+            ((C, bty, bm), jnp.float32),
         ),
         interpret=interpret,
     )

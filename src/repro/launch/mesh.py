@@ -5,8 +5,8 @@ jax device state — required because the dry-run must set
 xla_force_host_platform_device_count before first jax init, while smoke
 tests and benches must keep seeing 1 device.
 
-Mesh construction itself lives in repro.compat (the axis_types= kwarg and
-jax.sharding.AxisType only exist on jax >= 0.5).
+Mesh construction itself lives in repro.compat (``make_mesh`` with every
+axis Auto).
 """
 from __future__ import annotations
 

@@ -191,7 +191,9 @@ def build_gan_step(cfg: GANConfig, mesh: Mesh, *, settings=None,
     from the positional arg here; ``batch`` defaults to
     ``GAN_TRAIN_BATCH``); the individual kwargs are the deprecated
     spelling."""
-    from repro.train.trainer import _UNSET, _merge_legacy, gan_losses
+    from repro.train.trainer import (
+        _UNSET, _merge_legacy, check_gspmd_partitionable, gan_losses,
+    )
 
     st = _merge_legacy(settings, dict(
         overlap=overlap if overlap is not None else _UNSET,
@@ -218,6 +220,7 @@ def build_gan_step(cfg: GANConfig, mesh: Mesh, *, settings=None,
         ) + (z, real)
         return fn, args, meta
 
+    check_gspmd_partitionable(cfg)
     (gp, dp, z, real), (gspecs, dspecs, zspec, rspec), meta = \
         gan_input_specs(cfg, mesh, batch)
     gopt = jax.eval_shape(adamw_init, gp)

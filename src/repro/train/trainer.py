@@ -128,6 +128,20 @@ def _merge_legacy(settings: Optional[StepSettings], legacy: dict,
 
 
 # --------------------------------------------------------------- GAN loop
+def check_gspmd_partitionable(cfg: GANConfig) -> None:
+    """Raise if ``cfg`` runs compiled Pallas kernels: the GSPMD partitioner
+    cannot split a Mosaic kernel, so a meshed step with them must be the
+    shard_map one (``StepSettings(overlap=True)``), where each device runs
+    the kernels on its own shard."""
+    mosaic = [i for i in (cfg.deconv_impl, cfg.conv_impl)
+              if i.startswith("pallas") and not i.endswith("_interpret")]
+    if mosaic:
+        raise ValueError(
+            f"impls {mosaic} run Pallas TPU kernels, which GSPMD cannot "
+            "partition; build the meshed step with StepSettings(overlap=True)"
+        )
+
+
 def gan_losses(gp, dp, cfg: GANConfig, z, real, *, training=True):
     fake, g_stats = G.generator_apply(gp, cfg, z, training=training)
     d_fake, _ = G.discriminator_apply(dp, cfg, fake, training=training)
@@ -226,6 +240,7 @@ def make_gan_step(cfg: GANConfig, lr=_UNSET, b1=_UNSET, *,
         return jax.jit(step)
     if batch is None:
         raise ValueError("batch (global batch size) is required with mesh")
+    check_gspmd_partitionable(cfg)
     from repro.parallel import sharding as SH
 
     gsp, dsp, _ = SH.gan_param_specs(cfg, mesh)

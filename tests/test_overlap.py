@@ -173,7 +173,7 @@ def test_overlap_step_bucketing_invariance_and_parity():
             losses_1.append((float(m["g_loss"]), float(m["d_loss"])))
 
         mesh = make_mesh((8,), ("data",))
-        finals = []
+        finals, norms = [], []
         for bb in (OV.DEFAULT_BUCKET_BYTES, 8192):
             fn, meta = OV.build_gan_comm_step(
                 cfg, mesh, batch=B, donate=False, bucket_bytes=bb)
@@ -182,6 +182,7 @@ def test_overlap_step_bucketing_invariance_and_parity():
             if bb == 8192:
                 assert len(meta["g_plan"].buckets) > 1, meta["g_plan"]
             gp, dp, go, do = cp(gp0), cp(dp0), cp(go0), cp(do0)
+            plan_norms = []
             for s in range(3):
                 z = D.latent_batch(0, s, B, cfg.z_dim)
                 real = D.gan_batch(0, s, B, cfg.img_hw)
@@ -189,16 +190,27 @@ def test_overlap_step_bucketing_invariance_and_parity():
                 gl, dl = losses_1[s]
                 assert abs(float(m["g_loss"]) - gl) < 1e-3, (s, bb, float(m["g_loss"]), gl)
                 assert abs(float(m["d_loss"]) - dl) < 1e-3, (s, bb, float(m["d_loss"]), dl)
+                plan_norms.append([float(m["g_grad_norm"]), float(m["d_grad_norm"])])
             finals.append((gp, dp))
+            norms.append(plan_norms)
 
         check = lambda tol: lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=tol, rtol=tol)
         # parity with the single-device trajectory
         jax.tree.map(check(2e-3), finals[0][0], g1)
         jax.tree.map(check(2e-3), finals[0][1], d1)
-        # bucketing invariance: both plans land on (near-)identical params
-        jax.tree.map(check(1e-6), finals[0][0], finals[1][0])
-        jax.tree.map(check(1e-6), finals[0][1], finals[1][1])
+        # bucketing invariance.  The bucket plan changes the order of XLA's
+        # f32 reductions, so the reduced grads agree to rounding: their
+        # global norms (taken before AdamW) to 1e-5 relative.  The params
+        # agree only to 2 * steps * lr: AdamW divides each grad by its own
+        # magnitude, so a leaf whose exact grad is zero (a conv bias that
+        # feeds batchnorm) steps by up to lr along its rounding noise, whose
+        # sign the reduction order decides.
+        np.testing.assert_allclose(norms[0], norms[1], rtol=1e-5)
+        plan_tol = lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2 * 3 * 2e-4, rtol=0)
+        jax.tree.map(plan_tol, finals[0][0], finals[1][0])
+        jax.tree.map(plan_tol, finals[0][1], finals[1][1])
         print("OK")
         """
     )

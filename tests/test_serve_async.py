@@ -42,6 +42,13 @@ def _tiny_artgan(deconv_impl: str = "ref") -> "object":
     )
 
 
+def assert_bucket_close(out, want):
+    """A bucket-padded batched generate vs the unbatched one: XLA's f32
+    reduction order depends on the batch bucket, so the images agree to
+    rounding (largest difference seen: 1.2e-10), not bitwise."""
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
 def _gan_engine(batch=4):
     cfg = tiny_dcgan("ref")
     p_raw = G.generator_init(jax.random.PRNGKey(0), cfg)
@@ -199,7 +206,7 @@ def test_async_server_serves_and_stamps_slo():
     assert all(o.shape == outs[0].shape for o in outs)
     want, _ = G.generator_apply(p_raw, cfg, z, training=False)
     for o in outs:
-        np.testing.assert_array_equal(np.asarray(o), np.asarray(want))
+        assert_bucket_close(o, want)
     for f in futs:
         t = f.request.timing
         assert t is not None

@@ -51,6 +51,13 @@ def test_engine_matches_straightline_decode():
 
 
 # -------------------------------------------------------- GAN request queue
+def assert_bucket_close(out, want):
+    """A bucket-padded batched generate vs the unbatched one: XLA's f32
+    reduction order depends on the batch bucket, so the images agree to
+    rounding (largest difference seen: 1.2e-10), not bitwise."""
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
 def _gan_engine(batch=4):
     from repro.configs.gan_zoo import tiny_dcgan
     from repro.models import gan as G
@@ -86,7 +93,7 @@ def test_gan_queue_coalesces_small_requests():
     assert eng.served == 7
     for r in reqs:
         want, _ = G.generator_apply(p_raw, cfg, r.z, training=False)
-        np.testing.assert_array_equal(np.asarray(r.out), np.asarray(want))
+        assert_bucket_close(r.out, want)
 
 
 def test_gan_queue_run_preserves_order_and_outputs():
@@ -102,7 +109,7 @@ def test_gan_queue_run_preserves_order_and_outputs():
     assert eng.served == 11
     for z, o in zip(zs, outs):
         want, _ = G.generator_apply(p_raw, cfg, z, training=False)
-        np.testing.assert_array_equal(np.asarray(o), np.asarray(want))
+        assert_bucket_close(o, want)
 
 
 def test_gan_queue_rejects_oversized_request():

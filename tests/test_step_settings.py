@@ -52,3 +52,20 @@ def test_train_gan_settings_matches_legacy_kwargs():
     with pytest.warns(DeprecationWarning):
         out_old = train_gan(cfg, deconv_impl="ref", **kw)
     assert out_new["metrics"] == out_old["metrics"]
+
+
+@pytest.mark.parametrize("impls", [("pallas_chained", "lax"), ("ref", "pallas_chained")])
+def test_meshed_gspmd_step_refuses_mosaic_kernels(impls):
+    """GSPMD cannot partition a Mosaic kernel: the meshed step with a
+    compiled Pallas impl raises at build time and names the shard_map step,
+    which builds."""
+    from repro.compat import make_mesh
+
+    cfg = tiny_dcgan(*impls)
+    mesh = make_mesh((1,), ("data",))
+    with pytest.raises(ValueError, match="overlap=True"):
+        make_gan_step(cfg, settings=StepSettings(mesh=mesh, batch=4))
+    assert make_gan_step(cfg, settings=StepSettings(mesh=mesh, batch=4, overlap=True))
+    # the interpret twins run no Mosaic kernel: GSPMD may partition them
+    cfg_i = tiny_dcgan(*(f"{i}_interpret" if i.startswith("pallas") else i for i in impls))
+    assert make_gan_step(cfg_i, settings=StepSettings(mesh=mesh, batch=4))
