@@ -43,9 +43,6 @@ communication-efficient step from ``parallel.overlap`` (prefetched FSDP
 gathers, bucketed backward-order grad reduction, sync-BN, ZeRO block
 updates, int8 error-feedback compression by default) timed at constant
 per-device batch, with the per-step grad-reduction wire bytes recorded.
-``--profile`` attributes every sharded/weak-scaling point from the lowered
-HLO (collective counts, wire bytes, flops — ``launch.hlo_costs``) and drops
-jax profiler traces under ``--profile-dir``.
 
 ``--train-chaos`` runs the train-side chaos drill (``bench_train_chaos``):
 the resilient ``train_gan`` loop under injected NaN gradients, a persistent
@@ -526,40 +523,8 @@ def bench_conv1d(*, interpret: bool, smoke: bool, repeats: int = 3) -> dict:
     return out
 
 
-def profile_step(step_fn, args, n_devices: int, *, trace_dir=None, tag=""):
-    """Attribute a jitted step: static collective-vs-compute breakdown from
-    the lowered HLO via ``launch.hlo_costs.analyze_text`` (flops, HBM bytes,
-    collective wire bytes, per-op collective counts), plus an optional jax
-    profiler trace under ``trace_dir`` for timeline inspection.  This is
-    what turns the sharded slowdown curve from a guess into an attribution:
-    the per-device-count records show exactly how many collectives each
-    step issues and what they move."""
-    rec: dict = {}
-    try:
-        from repro.launch.hlo_costs import analyze_text
-
-        txt = step_fn.lower(*args).compile().as_text()
-        c = analyze_text(txt, n_devices)
-        rec.update(c)
-        comm = c.get("collective_wire_bytes_per_device") or 0
-        hbm = c.get("hbm_bytes_per_device") or 0
-        if comm + hbm:
-            rec["collective_bytes_fraction"] = comm / (comm + hbm)
-    except Exception as e:  # keep the bench alive on analyzer drift
-        rec["error"] = f"{type(e).__name__}: {e}"[:200]
-    if trace_dir:
-        d = os.path.join(trace_dir, tag)
-        os.makedirs(d, exist_ok=True)
-        with jax.profiler.trace(d):
-            for _ in range(3):
-                jax.block_until_ready(step_fn(*args))
-        rec["trace_dir"] = d
-    return rec
-
-
 def bench_sharded(
     requested: int, *, interpret: bool, smoke: bool, repeats: int = 3,
-    profile: bool = False, profile_dir=None,
 ) -> dict:
     """Per-device-count wall times of the full sharded GAN train step.
 
@@ -610,21 +575,12 @@ def bench_sharded(
         ms = time_one(step, (gp, dp, go, do, z, real), repeats) * 1e3
         out["step_ms"][str(d)] = ms
         print(f"train_step,sharded,{cfg.arch_id},devices={d},step={ms:.2f}")
-        if profile:
-            rec = profile_step(
-                step, (gp, dp, go, do, z, real), d,
-                trace_dir=profile_dir, tag=f"sharded_d{d}",
-            )
-            out.setdefault("profile", {})[str(d)] = rec
-            colls = rec.get("collectives_by_op")
-            print(f"train_step,sharded,profile,devices={d},collectives={colls}")
     return out
 
 
 def bench_weak_scaling(
     requested: int, *, interpret: bool, smoke: bool, repeats: int = 3,
     per_device_batch: int = 1, grad_compression="int8",
-    profile: bool = False, profile_dir=None,
 ) -> dict:
     """Weak scaling of the communication-efficient sharded GAN step: the
     global batch grows with the device count (``per_device_batch`` per
@@ -704,14 +660,6 @@ def bench_weak_scaling(
             }
         print(f"train_step,weak_scaling,{cfg.arch_id},devices={d},"
               f"batch={B},step={ms:.2f},per_dev={ms / d:.2f}")
-        if profile:
-            rec = profile_step(
-                step, args, d, trace_dir=profile_dir, tag=f"weak_d{d}",
-            )
-            out.setdefault("profile", {})[str(d)] = rec
-            colls = rec.get("collectives_by_op")
-            print(f"train_step,weak_scaling,profile,devices={d},"
-                  f"collectives={colls}")
     return out
 
 
@@ -851,13 +799,6 @@ def main(argv: list[str] | None = None) -> dict:
                     help="skip the per-layer sweep and emit only the "
                          "sharded per-device-count table (the multi-device "
                          "CI job: the tests job already gates the layers)")
-    ap.add_argument("--profile", action="store_true",
-                    help="attribute each sharded/weak-scaling point: "
-                         "collective-vs-compute breakdown from the lowered "
-                         "HLO (launch.hlo_costs) + a jax profiler trace "
-                         "under --profile-dir")
-    ap.add_argument("--profile-dir", default="artifacts/profile",
-                    help="where --profile writes jax profiler traces")
     ap.add_argument("--per-device-batch", type=int, default=1,
                     help="weak-scaling batch per device (global batch = "
                          "devices * this)")
@@ -948,8 +889,7 @@ def main(argv: list[str] | None = None) -> dict:
     if args.devices:
         report["sharded"] = bench_sharded(
             args.devices, interpret=interpret, smoke=args.smoke,
-            repeats=args.repeats, profile=args.profile,
-            profile_dir=args.profile_dir,
+            repeats=args.repeats,
         )
         report["weak_scaling"] = bench_weak_scaling(
             args.devices, interpret=interpret, smoke=args.smoke,
@@ -957,7 +897,6 @@ def main(argv: list[str] | None = None) -> dict:
             grad_compression=(
                 None if args.grad_compression == "none" else args.grad_compression
             ),
-            profile=args.profile, profile_dir=args.profile_dir,
         )
     if args.train_chaos:
         report["train_chaos"] = bench_train_chaos(smoke=args.smoke)

@@ -1,9 +1,11 @@
 """The JAX spellings the rest of the codebase imports from one place.
 
 Written for the installed jax 0.9.0: ``pltpu.CompilerParams``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map(check_vma=)`` and
-``jax.tree.*``.  Importers use ``from repro.compat import ...``, so a JAX
-upgrade that renames one of these is a one-file change.
+``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map(check_vma=)``,
+``jax.tree.*`` and the profiler's ``TraceMe`` (``TraceAnnotation``, and
+``is_enabled``, true only while a profiler session records).  Importers
+use ``from repro.compat import ...``, so a JAX upgrade that renames one of
+these is a one-file change.
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ __all__ = [
     "tree_leaves",
     "tree_flatten",
     "tree_unflatten",
+    "trace_annotation",
+    "profiler_active",
 ]
 
 tpu_compiler_params = pltpu.CompilerParams
@@ -26,6 +30,8 @@ tree_map = jax.tree.map
 tree_leaves = jax.tree.leaves
 tree_flatten = jax.tree.flatten
 tree_unflatten = jax.tree.unflatten
+trace_annotation = jax.profiler.TraceAnnotation
+profiler_active = jax.profiler.TraceAnnotation.is_enabled
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):

@@ -27,6 +27,11 @@ Per-workload entry points (the six 2D deconv engines and three conv
 engines) live in ``kernels/winograd_deconv.py`` as declarative
 instantiations of these builders.
 
+Each engine function takes ``layer``, the geometry tag its caller knows (e.g.
+``deconv_k5s2_1024to512``), and names its kernel ``<layer>_<pass>`` with
+pass ``fwd``, ``bwd_x`` or ``bwd_w``: that name becomes the kernel's HLO
+instruction name, the name a profiler trace shows for the device op.
+
 Maps the paper's PE array (Fig. 7) onto the TPU:
 
   pre-PE   -> two variants.  Unfused (winograd_domain_engine): host-side
@@ -384,7 +389,8 @@ def _engine_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("pos_idx", "sub_slices", "m2", "block_t", "block_n", "block_m", "interpret"),
+    static_argnames=("pos_idx", "sub_slices", "m2", "block_t", "block_n", "block_m", "interpret",
+                     "layer"),
 )
 def domain_engine(
     xw: jax.Array,  # (T, n2, N)
@@ -398,6 +404,7 @@ def domain_engine(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """Returns (T, S2*m2, M): per-tile sub-pixel outputs, sub-filter-major.
 
@@ -437,6 +444,7 @@ def domain_engine(
             ((C, bt, bm), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_fwd",
     )(xw_p, ww_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
     return out[:T, :, :M]
 
@@ -648,7 +656,7 @@ def _fused_epi_kernel(
     jax.jit,
     static_argnames=(
         "bt_mat", "pos_idx", "sub_slices", "m", "n", "ty", "tx", "m2", "phases",
-        "block_ty", "block_n", "block_m", "interpret",
+        "block_ty", "block_n", "block_m", "interpret", "layer",
         "out_mode", "activation", "stride", "padding", "out_h", "out_w",
     ),
 )
@@ -670,6 +678,7 @@ def fused_engine(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
     out_mode: str = "scratch",  # "scratch" | "nhwc" | "cells"
     activation: str = "none",
     scale: jax.Array | None = None,  # (M,) per-channel epilogue scale
@@ -781,6 +790,7 @@ def fused_engine(
             ((C, bty * tx, bm), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_fwd",
     )
 
     if out_mode == "scratch":
@@ -1012,7 +1022,8 @@ def _engine_bwd_x_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("pos_idx", "sub_slices", "m2", "n2", "block_t", "block_n", "block_m", "interpret"),
+    static_argnames=("pos_idx", "sub_slices", "m2", "n2", "block_t", "block_n", "block_m", "interpret",
+                     "layer"),
 )
 def domain_engine_bwd_x(
     g: jax.Array,  # (T, S2*m2, M) cotangent of the forward output
@@ -1027,6 +1038,7 @@ def domain_engine_bwd_x(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dxw (T, n2, N) of ``domain_engine``: the M axis becomes
     the accumulated grid axis; everything else mirrors the forward engine."""
@@ -1066,6 +1078,7 @@ def domain_engine_bwd_x(
             ((bt, n2, bn), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_bwd_x",
     )(g_p, ww_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
     return out[:T, :, :N]
 
@@ -1103,7 +1116,8 @@ def _engine_bwd_w_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("pos_idx", "sub_slices", "m2", "block_t", "block_n", "block_m", "interpret"),
+    static_argnames=("pos_idx", "sub_slices", "m2", "block_t", "block_n", "block_m", "interpret",
+                     "layer"),
 )
 def domain_engine_bwd_w(
     xw: jax.Array,  # (T, n2, N)
@@ -1117,6 +1131,7 @@ def domain_engine_bwd_w(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dww_packed (C, N, M) of ``domain_engine``: the tile axis T
     becomes the accumulated grid axis (the channel-accumulate of the forward
@@ -1157,6 +1172,7 @@ def domain_engine_bwd_w(
             ((C, bn, bm), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_bwd_w",
     )(xw_p, g_p, inv_packed, jnp.asarray(_const_operand((), pos_idx)))
     return out[:, :N, :M]
 
@@ -1284,7 +1300,7 @@ def _fused_bwd_x_kernel(
     jax.jit,
     static_argnames=(
         "bt_mat", "pos_idx", "sub_slices", "m", "n", "ty", "tx", "gy", "gx",
-        "m2", "phases", "block_ty", "block_n", "block_m", "interpret",
+        "m2", "phases", "block_ty", "block_n", "block_m", "interpret", "layer",
     ),
 )
 def fused_engine_bwd_x(
@@ -1307,6 +1323,7 @@ def fused_engine_bwd_x(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dcells (B, gy, gx, phases*m*m, N) of ``fused_engine``.
 
@@ -1379,6 +1396,7 @@ def fused_engine_bwd_x(
             (((h + bty) * tx, phases * n * n, bn), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_bwd_x",
     )(g_p, g_p, ww_p, inv_packed, jnp.asarray(_const_operand(bt_mat, pos_idx)))
     out = out[:, :, :, :, :N]
     if out.shape[1] < gy:  # cell rows past the tile extent are structurally zero
@@ -1432,7 +1450,7 @@ def _fused_bwd_w_kernel(
     jax.jit,
     static_argnames=(
         "bt_mat", "pos_idx", "sub_slices", "m", "n", "ty", "tx", "m2", "phases",
-        "block_ty", "block_n", "block_m", "interpret",
+        "block_ty", "block_n", "block_m", "interpret", "layer",
     ),
 )
 def fused_engine_bwd_w(
@@ -1453,6 +1471,7 @@ def fused_engine_bwd_w(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dww_packed (C, N, M) of ``fused_engine``: the grid reduces over
     (batch x tile-row blocks), re-deriving each block's transformed tiles
@@ -1519,6 +1538,7 @@ def fused_engine_bwd_w(
             ((C, bn, bm), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_bwd_w",
     )(cells_p, cells_p, g_p, inv_packed,
       jnp.asarray(_const_operand(bt_mat, pos_idx)))
     return out[:, :N, :M]
@@ -1664,7 +1684,7 @@ def _fused1d_epi_kernel(
     jax.jit,
     static_argnames=(
         "bt_mat", "pos_idx", "sub_slices", "m", "n", "ty", "phases",
-        "block_ty", "block_n", "block_m", "interpret",
+        "block_ty", "block_n", "block_m", "interpret", "layer",
         "out_mode", "activation", "stride",
     ),
 )
@@ -1684,6 +1704,7 @@ def winograd_conv1d_fused_engine(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
     out_mode: str = "nlc",  # "scratch" | "nlc"
     activation: str = "none",
     scale: jax.Array | None = None,  # (M,) per-channel epilogue scale
@@ -1743,6 +1764,7 @@ def winograd_conv1d_fused_engine(
             ((C, bty, bm), jnp.float32),
         ),
         interpret=interpret,
+        name=f"{layer}_fwd",
     )
 
     if out_mode == "scratch":
@@ -1845,6 +1867,7 @@ def winograd_conv1d_fused_bwd_x(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dcells (B, gy, phases*m, N) of the 1D fused engine: the packed
     MXU contraction runs in ``domain_engine_bwd_x``; the rank-1 B-scatter
@@ -1855,6 +1878,7 @@ def winograd_conv1d_fused_bwd_x(
         g.reshape(B * ty, s2m, M), ww_packed, inv_packed,
         pos_idx=pos_idx, sub_slices=sub_slices, m2=m, n2=phases * n,
         block_t=block_t, block_n=block_n, block_m=block_m, interpret=interpret,
+        layer=layer,
     )  # (B*ty, phases*n, N)
     N = dxw.shape[2]
     b_mat = jnp.asarray(bt_mat, jnp.float32).T  # B = (B^T)^T
@@ -1890,6 +1914,7 @@ def winograd_conv1d_fused_bwd_w(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",  # geometry tag the kernels are named by
 ) -> jax.Array:
     """dL/dww_packed (C, N, M) of the 1D fused engine: recompute the rank-1
     transformed tiles host-side, reduce the tile axis in
@@ -1900,5 +1925,6 @@ def winograd_conv1d_fused_bwd_w(
         xw.astype(cells.dtype), g.reshape(B * ty, s2m, M), inv_packed,
         pos_idx=pos_idx, sub_slices=sub_slices, m2=m,
         block_t=block_t, block_n=block_n, block_m=block_m, interpret=interpret,
+        layer=layer,
     )
 

@@ -204,43 +204,50 @@ def prepack(w: jax.Array, dims: DeconvDims, m: int = 2, r: int = 3) -> PackedDec
 # machinery — see kernels/winograd_deconv.py).  ref.py never runs here.
 
 
+def _layer_tag(kind: str, kernel: int, stride: int, ww) -> str:
+    """``<kind>_k<K>s<S>_<N>to<M>`` (``ww`` the packed (..., N, M) weight):
+    the geometry tag the engines name their kernels by."""
+    return f"{kind}_k{kernel}s{stride}_{ww.shape[-2]}to{ww.shape[-1]}"
+
+
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
 )
 def _engine_vjp(
     xw, ww, inv, pos_idx, sub_slices, m2, interpret, bt, bn, bm,
-    bwd_bt, bwd_bn, bwd_bm,
+    bwd_bt, bwd_bn, bwd_bm, layer,
 ):
     return winograd_domain_engine(
         xw, ww, inv, pos_idx=pos_idx, sub_slices=sub_slices, m2=m2,
-        interpret=interpret, block_t=bt, block_n=bn, block_m=bm,
+        interpret=interpret, block_t=bt, block_n=bn, block_m=bm, layer=layer,
     )
 
 
 def _engine_fwd(
     xw, ww, inv, pos_idx, sub_slices, m2, interpret, bt, bn, bm,
-    bwd_bt, bwd_bn, bwd_bm,
+    bwd_bt, bwd_bn, bwd_bm, layer,
 ):
     y = _engine_vjp(
         xw, ww, inv, pos_idx, sub_slices, m2, interpret, bt, bn, bm,
-        bwd_bt, bwd_bn, bwd_bm,
+        bwd_bt, bwd_bn, bwd_bm, layer,
     )
     return y, (xw, ww, inv)
 
 
 def _engine_bwd(
     pos_idx, sub_slices, m2, interpret, bt, bn, bm, bwd_bt, bwd_bn, bwd_bm,
-    res, g,
+    layer, res, g,
 ):
     xw, ww, inv = res
     dxw = winograd_domain_engine_bwd_x(
         g, ww, inv, pos_idx=pos_idx, sub_slices=sub_slices, m2=m2,
         n2=xw.shape[1], interpret=interpret,
-        block_t=bwd_bt, block_n=bwd_bn, block_m=bwd_bm,
+        block_t=bwd_bt, block_n=bwd_bn, block_m=bwd_bm, layer=layer,
     )
     dww = winograd_domain_engine_bwd_w(
         xw, g, inv, pos_idx=pos_idx, sub_slices=sub_slices, m2=m2,
         interpret=interpret, block_t=bwd_bt, block_n=bwd_bn, block_m=bwd_bm,
+        layer=layer,
     )
     return dxw.astype(xw.dtype), dww.astype(ww.dtype), jnp.zeros_like(inv)
 
@@ -352,35 +359,35 @@ def cells_to_next(
 
 @functools.partial(
     jax.custom_vjp,
-    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17),
+    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17, 18),
 )
 def _fused_pre_vjp(
     cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, tx, m2,
-    interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm,
+    interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm, layer,
 ):
     """Fused pre-PE engine with a custom VJP; both cotangents run as fused
     Pallas kernels too (the input cotangent emits the cell layout directly)."""
     return winograd_fused_pre_engine(
         cells, ww, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx, m2=m2,
-        interpret=interpret, block_ty=bty, block_n=bn, block_m=bm,
+        interpret=interpret, block_ty=bty, block_n=bn, block_m=bm, layer=layer,
     )
 
 
 def _fused_pre_fwd(
     cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, tx, m2,
-    interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm,
+    interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm, layer,
 ):
     y = _fused_pre_vjp(
         cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, tx, m2,
-        interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm,
+        interpret, bty, bn, bm, bwd_bty, bwd_bn, bwd_bm, layer,
     )
     return y, (cells, ww, inv)
 
 
 def _fused_pre_bwd(
     bt_mat, pos_idx, sub_slices, m, n, ty, tx, m2, interpret, bty, bn, bm,
-    bwd_bty, bwd_bn, bwd_bm, res, g,
+    bwd_bty, bwd_bn, bwd_bm, layer, res, g,
 ):
     cells, ww, inv = res
     gy, gx = cells.shape[1], cells.shape[2]
@@ -388,12 +395,13 @@ def _fused_pre_bwd(
         g, ww, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx,
         gy=gy, gx=gx, m2=m2, interpret=interpret,
-        block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm, layer=layer,
     )
     dww = winograd_fused_pre_engine_bwd_w(
         cells, g, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx, m2=m2,
         interpret=interpret, block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        layer=layer,
     )
     return dcells.astype(cells.dtype), dww.astype(ww.dtype), jnp.zeros_like(inv)
 
@@ -409,10 +417,11 @@ _fused_pre_vjp.defvjp(_fused_pre_fwd, _fused_pre_bwd)
 # then the existing fused Pallas backward engines — no new backward kernels.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 21)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 22)))
 def _fused_epi_vjp(
     cells, ww, inv, scale, bias, bt_mat, pos_idx, sub_slices, m, n, ty, tx,
     m2, out_mode, activation, stride, padding, out_h, out_w, interpret, blocks,
+    layer,
 ):
     bty, bn, bm = blocks[:3]
     return winograd_fused_pre_engine(
@@ -420,18 +429,19 @@ def _fused_epi_vjp(
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx, m2=m2,
         block_ty=bty, block_n=bn, block_m=bm, interpret=interpret,
         out_mode=out_mode, activation=activation, scale=scale, bias=bias,
-        stride=stride, padding=padding, out_h=out_h, out_w=out_w,
+        stride=stride, padding=padding, out_h=out_h, out_w=out_w, layer=layer,
     )
 
 
 def _fused_epi_fwd(
     cells, ww, inv, scale, bias, bt_mat, pos_idx, sub_slices, m, n, ty, tx,
     m2, out_mode, activation, stride, padding, out_h, out_w, interpret, blocks,
+    layer,
 ):
     y = _fused_epi_vjp(
         cells, ww, inv, scale, bias, bt_mat, pos_idx, sub_slices, m, n, ty,
         tx, m2, out_mode, activation, stride, padding, out_h, out_w,
-        interpret, blocks,
+        interpret, blocks, layer,
     )
     # the post-activation output doubles as the activation residual: every
     # supported activation's derivative (and, for the scale cotangent, its
@@ -477,7 +487,7 @@ def _epilogue_cotangent(g_img, y_img, scale, bias, activation, M):
 
 def _fused_epi_bwd(
     bt_mat, pos_idx, sub_slices, m, n, ty, tx, m2, out_mode, activation,
-    stride, padding, out_h, out_w, interpret, blocks, res, g,
+    stride, padding, out_h, out_w, interpret, blocks, layer, res, g,
 ):
     cells, ww, inv, scale, bias, y_out = res
     _, _, _, bwd_bty, bwd_bn, bwd_bm = blocks
@@ -527,7 +537,7 @@ def _fused_epi_bwd(
         g_scr, ww, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx,
         gy=gy, gx=gx, m2=m2, interpret=interpret,
-        block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm, layer=layer,
     )
     if dcells.shape[-1] < cells.shape[-1]:
         # a chained input carries block-padded trailing channels the engine
@@ -540,6 +550,7 @@ def _fused_epi_bwd(
         cells, g_scr, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty, tx=tx, m2=m2,
         interpret=interpret, block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        layer=layer,
     )[:, : ww.shape[1], :]  # chained inputs may be channel-padded past N
     ds = None if scale is None else dscale.astype(scale.dtype)
     db = None if bias is None else dbias.astype(bias.dtype)
@@ -607,6 +618,7 @@ def winograd_deconv2d_cells(
             cells, packed.ww, packed.inv, scale, bias, bt_mat, pos_idx,
             sub_slices, m, tf.n, ty, tx, m2, out_mode, epilogue, dims.stride,
             dims.padding, HO, WO, interpret, blocks,
+            _layer_tag("deconv", dims.kernel, dims.stride, packed.ww),
         )
     elif backend == "ref":
         y = _ref.fused_epilogue_engine_ref(
@@ -711,6 +723,7 @@ def winograd_deconv2d_packed(
     bwd_n = block_n if bwd_block_n is None else bwd_block_n
     bwd_m = block_m if bwd_block_m is None else bwd_block_m
     bwd_ty = block_ty if bwd_block_ty is None else bwd_block_ty
+    layer = _layer_tag("deconv", dims.kernel, S, packed.ww)
     if fuse_pre:
         cells = cells_layout(x_pad, ty, tx, m, tf.n).astype(x.dtype)
         bt_mat = tuple(tuple(float(v) for v in row) for row in tf.BT)
@@ -718,7 +731,7 @@ def winograd_deconv2d_packed(
             y = _fused_pre_vjp(
                 cells, packed.ww, packed.inv, bt_mat, pos_idx, sub_slices,
                 m, tf.n, ty, tx, m2, interpret, block_ty, block_n, block_m,
-                bwd_ty, bwd_n, bwd_m,
+                bwd_ty, bwd_n, bwd_m, layer,
             )
         elif backend == "ref":
             y = _ref.fused_pre_engine_ref(
@@ -735,7 +748,7 @@ def winograd_deconv2d_packed(
         if backend == "pallas":
             y = _engine_vjp(
                 xw_mat, packed.ww, packed.inv, pos_idx, sub_slices, m2,
-                interpret, block_t, block_n, block_m, bwd_t, bwd_n, bwd_m,
+                interpret, block_t, block_n, block_m, bwd_t, bwd_n, bwd_m, layer,
             )
         elif backend == "ref":
             y = _ref.engine_ref(
@@ -1035,10 +1048,10 @@ def conv_cells_to_next(
 # discriminator never runs a reference conv.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 18)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 19)))
 def _conv_epi_vjp(
     cells, ww, inv, scale, bias, bt_mat, pos_idx, m, n, ty, tx, s2,
-    out_mode, activation, out_h, out_w, interpret, blocks,
+    out_mode, activation, out_h, out_w, interpret, blocks, layer,
 ):
     bty, bn, bm = blocks[:3]
     return winograd_conv_fused_engine(
@@ -1046,24 +1059,24 @@ def _conv_epi_vjp(
         pos_idx=pos_idx, m=m, n=n, ty=ty, tx=tx, s2=s2,
         block_ty=bty, block_n=bn, block_m=bm, interpret=interpret,
         out_mode=out_mode, activation=activation, scale=scale, bias=bias,
-        out_h=out_h, out_w=out_w,
+        out_h=out_h, out_w=out_w, layer=layer,
     )
 
 
 def _conv_epi_fwd(
     cells, ww, inv, scale, bias, bt_mat, pos_idx, m, n, ty, tx, s2,
-    out_mode, activation, out_h, out_w, interpret, blocks,
+    out_mode, activation, out_h, out_w, interpret, blocks, layer,
 ):
     y = _conv_epi_vjp(
         cells, ww, inv, scale, bias, bt_mat, pos_idx, m, n, ty, tx, s2,
-        out_mode, activation, out_h, out_w, interpret, blocks,
+        out_mode, activation, out_h, out_w, interpret, blocks, layer,
     )
     return y, (cells, ww, inv, scale, bias, y)
 
 
 def _conv_epi_bwd(
     bt_mat, pos_idx, m, n, ty, tx, s2, out_mode, activation, out_h, out_w,
-    interpret, blocks, res, g,
+    interpret, blocks, layer, res, g,
 ):
     cells, ww, inv, scale, bias, y_out = res
     _, _, _, bwd_bty, bwd_bn, bwd_bm = blocks
@@ -1100,6 +1113,7 @@ def _conv_epi_bwd(
         g_scr, ww, inv, bt_mat,
         pos_idx=pos_idx, m=m, n=n, ty=ty, tx=tx, gy=gy, gx=gx, s2=s2,
         interpret=interpret, block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        layer=layer,
     )
     if dcells.shape[-1] < cells.shape[-1]:
         # a chained input carries block-padded trailing channels the engine
@@ -1112,6 +1126,7 @@ def _conv_epi_bwd(
         cells, g_scr, inv, bt_mat,
         pos_idx=pos_idx, m=m, n=n, ty=ty, tx=tx, s2=s2,
         interpret=interpret, block_ty=bwd_bty, block_n=bwd_bn, block_m=bwd_bm,
+        layer=layer,
     )[:, : ww.shape[1], :]  # chained inputs may be channel-padded past N
     ds = None if scale is None else dscale.astype(scale.dtype)
     db = None if bias is None else dbias.astype(bias.dtype)
@@ -1176,6 +1191,7 @@ def winograd_conv2d_cells(
         y = _conv_epi_vjp(
             cells, packed.ww, packed.inv, scale, bias, bt_mat, pos_idx,
             m, tf.n, ty, tx, s2, out_mode, epilogue, HO, WO, interpret, blocks,
+            _layer_tag("conv", cdims.kernel, cdims.stride, packed.ww),
         )
     elif backend == "ref":
         y = _ref.conv_engine_ref(
@@ -1368,9 +1384,10 @@ def conv1d_cells(x_pad: jax.Array, ty: int, m: int, n: int) -> jax.Array:
     return x_pad.reshape(B, gy, m, N)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 12)))
 def _engine1d_vjp(
     cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, stride, interpret_blocks,
+    layer,
 ):
     """1D fused engine with a custom VJP: forward in "nlc" mode (the padded
     interleave), dL/dww through the rank-agnostic Pallas domain backward,
@@ -1381,22 +1398,23 @@ def _engine1d_vjp(
         cells, ww, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty,
         block_ty=bty, block_n=bn, block_m=bm, interpret=interpret,
-        out_mode="nlc", stride=stride,
+        out_mode="nlc", stride=stride, layer=layer,
     )
 
 
 def _engine1d_fwd(
     cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, stride, interpret_blocks,
+    layer,
 ):
     y = _engine1d_vjp(
         cells, ww, inv, bt_mat, pos_idx, sub_slices, m, n, ty, stride,
-        interpret_blocks,
+        interpret_blocks, layer,
     )
     return y, (cells, ww, inv)
 
 
 def _engine1d_bwd(
-    bt_mat, pos_idx, sub_slices, m, n, ty, stride, interpret_blocks, res, g,
+    bt_mat, pos_idx, sub_slices, m, n, ty, stride, interpret_blocks, layer, res, g,
 ):
     cells, ww, inv = res
     interpret, blocks = interpret_blocks
@@ -1412,7 +1430,7 @@ def _engine1d_bwd(
         g_scr, ww, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty,
         gy=cells.shape[1], block_t=bwd_bt, block_n=bwd_bn, block_m=bwd_bm,
-        interpret=interpret,
+        interpret=interpret, layer=layer,
     )
     if dcells.shape[-1] < cells.shape[-1]:
         # a chained input carries block-padded trailing channels the engine
@@ -1424,6 +1442,7 @@ def _engine1d_bwd(
         cells, g_scr, inv, bt_mat,
         pos_idx=pos_idx, sub_slices=sub_slices, m=m, n=n, ty=ty,
         block_t=bwd_bt, block_n=bwd_bn, block_m=bwd_bm, interpret=interpret,
+        layer=layer,
     )[:, : ww.shape[1], :]  # chained inputs may be channel-padded past N
     return dcells.astype(cells.dtype), dww.astype(ww.dtype), jnp.zeros_like(inv)
 
@@ -1492,6 +1511,7 @@ def winograd_conv1d_packed(
         y = _engine1d_vjp(
             cells, packed.ww, packed.inv, bt_mat, pos_idx, sub_slices,
             m, n, ty, 1, (interpret, blocks),
+            _layer_tag("conv1d", kernel, 1, packed.ww),
         )
     elif backend == "ref":
         y = _ref.conv1d_engine_ref(
@@ -1567,6 +1587,7 @@ def winograd_deconv1d_packed(
         y = _engine1d_vjp(
             cells, packed.ww, packed.inv, bt_mat, pos_idx, sub_slices,
             m, tf.n, ty, dims.stride, (interpret, blocks),
+            _layer_tag("deconv1d", dims.kernel, dims.stride, packed.ww),
         )
     elif backend == "ref":
         y = _ref.conv1d_engine_ref(

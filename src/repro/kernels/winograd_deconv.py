@@ -72,6 +72,7 @@ def winograd_conv_fused_engine(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",
     out_mode: str = "nhwc",  # "nhwc" | "cells"
     activation: str = "none",
     scale: jax.Array | None = None,  # (M,) per-channel epilogue scale
@@ -95,6 +96,7 @@ def winograd_conv_fused_engine(
         phases=s2,
         block_ty=block_ty, block_n=block_n, block_m=block_m,
         interpret=interpret,
+        layer=layer,
         out_mode=out_mode, activation=activation, scale=scale, bias=bias,
         stride=1, padding=0, out_h=out_h, out_w=out_w,
     )
@@ -118,6 +120,7 @@ def winograd_conv_fused_bwd_x(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",
 ) -> jax.Array:
     """dL/dcells of the conv engine on the generic backward builder (the
     reverse line buffer runs once per phase)."""
@@ -130,6 +133,7 @@ def winograd_conv_fused_bwd_x(
         phases=s2,
         block_ty=block_ty, block_n=block_n, block_m=block_m,
         interpret=interpret,
+        layer=layer,
     )
 
 
@@ -149,6 +153,7 @@ def winograd_conv_fused_bwd_w(
     block_n: int = 128,
     block_m: int = 128,
     interpret: bool = False,
+    layer: str = "winograd",
 ) -> jax.Array:
     """dL/dww_packed of the conv engine on the generic backward builder
     (phase xw recomputed from cells in VMEM)."""
@@ -161,4 +166,5 @@ def winograd_conv_fused_bwd_w(
         phases=s2,
         block_ty=block_ty, block_n=block_n, block_m=block_m,
         interpret=interpret,
+        layer=layer,
     )

@@ -562,51 +562,52 @@ def _chained_deconv_trunk(
     cells = kops.cells_from_image(h, cfg.deconvs[0].dims)
     img = None
     for i, d in enumerate(cfg.deconvs):
-        packed = _packed_of(p[f"deconv{i}"], d.dims)
-        has_bn = d.norm == "batch"
-        nxt = cfg.deconvs[i + 1].dims if i + 1 < len(cfg.deconvs) else None
-        out_hw = (d.dims.out_size(hw[0]), d.dims.out_size(hw[1]))
-        aligned = nxt is not None and kops.chain_aligned(d.dims, nxt)
-        if training and has_bn:
-            if aligned:
-                emitted = kops.winograd_deconv2d_cells(
-                    cells, packed, d.dims, hw, emit_cells=True, **kw,
-                )
-                y_cells, stats = _bn_act_cells(
-                    p[f"deconv{i}_bn"], emitted, out_hw, act=d.act,
-                    padding=d.dims.padding,
-                )
-                cells = kops.cells_to_next(y_cells, d.dims, nxt, out_hw)
-            else:  # misaligned hop (or BN on the last layer): NHWC fallback
-                img = kops.winograd_deconv2d_cells(cells, packed, d.dims, hw, **kw)
-                img, stats = L.batchnorm(p[f"deconv{i}_bn"], img, training=True)
-                img = L.ACTIVATIONS[d.act](img)
-                if nxt is not None:
-                    cells = kops.cells_from_image(img, nxt)
-            new_stats[f"deconv{i}_bn"] = stats
-        else:
-            scale, bias = (
-                _bn_eval_affine(p[f"deconv{i}_bn"]) if has_bn else (None, None)
-            )
-            if has_bn:
-                new_stats[f"deconv{i}_bn"] = {
-                    "mean": p[f"deconv{i}_bn"]["mean"],
-                    "var": p[f"deconv{i}_bn"]["var"],
-                }
-            if aligned:
-                emitted = kops.winograd_deconv2d_cells(
-                    cells, packed, d.dims, hw,
-                    epilogue=d.act, scale=scale, bias=bias, emit_cells=True, **kw,
-                )
-                cells = kops.cells_to_next(emitted, d.dims, nxt, out_hw)
+        with jax.named_scope(f"g.deconv{i}"):
+            packed = _packed_of(p[f"deconv{i}"], d.dims)
+            has_bn = d.norm == "batch"
+            nxt = cfg.deconvs[i + 1].dims if i + 1 < len(cfg.deconvs) else None
+            out_hw = (d.dims.out_size(hw[0]), d.dims.out_size(hw[1]))
+            aligned = nxt is not None and kops.chain_aligned(d.dims, nxt)
+            if training and has_bn:
+                if aligned:
+                    emitted = kops.winograd_deconv2d_cells(
+                        cells, packed, d.dims, hw, emit_cells=True, **kw,
+                    )
+                    y_cells, stats = _bn_act_cells(
+                        p[f"deconv{i}_bn"], emitted, out_hw, act=d.act,
+                        padding=d.dims.padding,
+                    )
+                    cells = kops.cells_to_next(y_cells, d.dims, nxt, out_hw)
+                else:  # misaligned hop (or BN on the last layer): NHWC fallback
+                    img = kops.winograd_deconv2d_cells(cells, packed, d.dims, hw, **kw)
+                    img, stats = L.batchnorm(p[f"deconv{i}_bn"], img, training=True)
+                    img = L.ACTIVATIONS[d.act](img)
+                    if nxt is not None:
+                        cells = kops.cells_from_image(img, nxt)
+                new_stats[f"deconv{i}_bn"] = stats
             else:
-                img = kops.winograd_deconv2d_cells(
-                    cells, packed, d.dims, hw,
-                    epilogue=d.act, scale=scale, bias=bias, **kw,
+                scale, bias = (
+                    _bn_eval_affine(p[f"deconv{i}_bn"]) if has_bn else (None, None)
                 )
-                if nxt is not None:
-                    cells = kops.cells_from_image(img, nxt)
-        hw = out_hw
+                if has_bn:
+                    new_stats[f"deconv{i}_bn"] = {
+                        "mean": p[f"deconv{i}_bn"]["mean"],
+                        "var": p[f"deconv{i}_bn"]["var"],
+                    }
+                if aligned:
+                    emitted = kops.winograd_deconv2d_cells(
+                        cells, packed, d.dims, hw,
+                        epilogue=d.act, scale=scale, bias=bias, emit_cells=True, **kw,
+                    )
+                    cells = kops.cells_to_next(emitted, d.dims, nxt, out_hw)
+                else:
+                    img = kops.winograd_deconv2d_cells(
+                        cells, packed, d.dims, hw,
+                        epilogue=d.act, scale=scale, bias=bias, **kw,
+                    )
+                    if nxt is not None:
+                        cells = kops.cells_from_image(img, nxt)
+            hw = out_hw
     return img, new_stats
 
 
@@ -640,11 +641,12 @@ def generator_apply(
         img, trunk_stats = _chained_deconv_trunk(p, cfg, h, training=training)
         return img, {**new_stats, **trunk_stats}
     for i, d in enumerate(cfg.deconvs):
-        h = _deconv_apply(cfg.deconv_impl, h, p[f"deconv{i}"], d.dims)
-        if d.norm == "batch":
-            h, s = L.batchnorm(p[f"deconv{i}_bn"], h, training=training)
-            new_stats[f"deconv{i}_bn"] = s
-        h = L.ACTIVATIONS[d.act](h)
+        with jax.named_scope(f"g.deconv{i}"):
+            h = _deconv_apply(cfg.deconv_impl, h, p[f"deconv{i}"], d.dims)
+            if d.norm == "batch":
+                h, s = L.batchnorm(p[f"deconv{i}_bn"], h, training=training)
+                new_stats[f"deconv{i}_bn"] = s
+            h = L.ACTIVATIONS[d.act](h)
     return h, new_stats
 
 
@@ -740,56 +742,57 @@ def _chained_conv_trunk(
     h_img = None
     n_layers = len(dims)
     for i, cd in enumerate(dims):
-        wd = p[f"conv{i}"]
-        kw = dict(base_kw)
-        if kw.get("backend") == "pallas" and "ww" in wd:
-            kw.update(DECONV_BLOCKS.get(
-                (cfg.conv_impl, cd, wd["ww"].shape[1], wd["ww"].shape[2]), {}
-            ))
-        packed = _packed_conv_of(wd, cd)
-        b = wd["b"].astype(jnp.float32)
-        has_bn = f"conv{i}_bn" in p
-        last = i + 1 >= n_layers
-        out_hw = (cd.out_size(hw[0]), cd.out_size(hw[1]))
-        aligned = not last and kops.conv_chain_aligned(cd, dims[i + 1])
-        if training and has_bn:
-            emitted = kops.winograd_conv2d_cells(
-                cells, packed, cd, hw, bias=b, emit_cells=True, **kw,
-            )
-            y_cells, stats = _bn_act_cells(
-                p[f"conv{i}_bn"], emitted, out_hw, act="leaky_relu",
-            )
-            new_stats[f"conv{i}_bn"] = stats
-            if aligned:
-                cells = kops.conv_cells_to_next(y_cells, cd, dims[i + 1], out_hw)
-            else:
-                h_img = _cells_to_image(y_cells, out_hw)
-                if not last:
-                    cells = kops.conv_cells_from_image(h_img, dims[i + 1])
-        else:
-            if has_bn:
-                a, bb = _bn_eval_affine(p[f"conv{i}_bn"])
-                scale, bias = a, a * b + bb
-                new_stats[f"conv{i}_bn"] = {
-                    "mean": p[f"conv{i}_bn"]["mean"],
-                    "var": p[f"conv{i}_bn"]["var"],
-                }
-            else:
-                scale, bias = None, b
-            if aligned:
+        with jax.named_scope(f"d.conv{i}"):
+            wd = p[f"conv{i}"]
+            kw = dict(base_kw)
+            if kw.get("backend") == "pallas" and "ww" in wd:
+                kw.update(DECONV_BLOCKS.get(
+                    (cfg.conv_impl, cd, wd["ww"].shape[1], wd["ww"].shape[2]), {}
+                ))
+            packed = _packed_conv_of(wd, cd)
+            b = wd["b"].astype(jnp.float32)
+            has_bn = f"conv{i}_bn" in p
+            last = i + 1 >= n_layers
+            out_hw = (cd.out_size(hw[0]), cd.out_size(hw[1]))
+            aligned = not last and kops.conv_chain_aligned(cd, dims[i + 1])
+            if training and has_bn:
                 emitted = kops.winograd_conv2d_cells(
-                    cells, packed, cd, hw, epilogue="leaky_relu",
-                    scale=scale, bias=bias, emit_cells=True, **kw,
+                    cells, packed, cd, hw, bias=b, emit_cells=True, **kw,
                 )
-                cells = kops.conv_cells_to_next(emitted, cd, dims[i + 1], out_hw)
+                y_cells, stats = _bn_act_cells(
+                    p[f"conv{i}_bn"], emitted, out_hw, act="leaky_relu",
+                )
+                new_stats[f"conv{i}_bn"] = stats
+                if aligned:
+                    cells = kops.conv_cells_to_next(y_cells, cd, dims[i + 1], out_hw)
+                else:
+                    h_img = _cells_to_image(y_cells, out_hw)
+                    if not last:
+                        cells = kops.conv_cells_from_image(h_img, dims[i + 1])
             else:
-                h_img = kops.winograd_conv2d_cells(
-                    cells, packed, cd, hw, epilogue="leaky_relu",
-                    scale=scale, bias=bias, **kw,
-                )
-                if not last:
-                    cells = kops.conv_cells_from_image(h_img, dims[i + 1])
-        hw = out_hw
+                if has_bn:
+                    a, bb = _bn_eval_affine(p[f"conv{i}_bn"])
+                    scale, bias = a, a * b + bb
+                    new_stats[f"conv{i}_bn"] = {
+                        "mean": p[f"conv{i}_bn"]["mean"],
+                        "var": p[f"conv{i}_bn"]["var"],
+                    }
+                else:
+                    scale, bias = None, b
+                if aligned:
+                    emitted = kops.winograd_conv2d_cells(
+                        cells, packed, cd, hw, epilogue="leaky_relu",
+                        scale=scale, bias=bias, emit_cells=True, **kw,
+                    )
+                    cells = kops.conv_cells_to_next(emitted, cd, dims[i + 1], out_hw)
+                else:
+                    h_img = kops.winograd_conv2d_cells(
+                        cells, packed, cd, hw, epilogue="leaky_relu",
+                        scale=scale, bias=bias, **kw,
+                    )
+                    if not last:
+                        cells = kops.conv_cells_from_image(h_img, dims[i + 1])
+            hw = out_hw
     return L.linear(p["head"], h_img.reshape(h_img.shape[0], -1)), new_stats
 
 
@@ -808,12 +811,13 @@ def discriminator_apply(
     h, new_stats = img, {}
     i = 0
     while f"conv{i}" in p:
-        h = _disc_conv_apply(impl, h, p[f"conv{i}"], dims[i])
-        if f"conv{i}_bn" in p:
-            h, s = L.batchnorm(p[f"conv{i}_bn"], h, training=training)
-            new_stats[f"conv{i}_bn"] = s
-        h = L.leaky_relu(h)
-        i += 1
+        with jax.named_scope(f"d.conv{i}"):
+            h = _disc_conv_apply(impl, h, p[f"conv{i}"], dims[i])
+            if f"conv{i}_bn" in p:
+                h, s = L.batchnorm(p[f"conv{i}_bn"], h, training=training)
+                new_stats[f"conv{i}_bn"] = s
+            h = L.leaky_relu(h)
+            i += 1
     return L.linear(p["head"], h.reshape(h.shape[0], -1)), new_stats
 
 

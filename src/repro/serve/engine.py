@@ -27,6 +27,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import GANConfig, LMConfig
 from repro.models import lm as LM
 from repro.serve.faults import (
@@ -606,7 +607,10 @@ class GanServeEngine:
         guard, capped exponential-backoff retries that never run past a
         request's absolute deadline (t_submit + deadline_ms), and circuit-
         breaker accounting on the final outcome.  Total isolation: no
-        exception escapes to the caller."""
+        exception escapes to the caller.  In a profiler session the
+        assembly, generate and completion of each attempt are
+        ``gan.serve.*`` spans (``repro.obs``) tagged with the dispatch
+        index, its rows and its bucket."""
         res = self.archs[arch]
         pending = list(reqs)
         attempt = 0
@@ -616,6 +620,7 @@ class GanServeEngine:
                 r.attempts += 1
             b = sum(r.size for r in pending)
             k = self.bucket_for(b)
+            tags = dict(dispatch=dispatch_idx, rows=b, bucket=k)
             try:
                 fault = None if plan is None else plan.draw(
                     arch=arch, rids=tuple(r.rid for r in pending),
@@ -628,20 +633,22 @@ class GanServeEngine:
                         f"injected fault (arch={arch}, "
                         f"dispatch={dispatch_idx}, attempt={attempt})"
                     )
-                z_all = jnp.concatenate([r.z for r in pending], axis=0)
-                z_pad = jnp.pad(
-                    z_all, ((0, k - b),) + ((0, 0),) * (z_all.ndim - 1)
-                )
-                imgs = res._generate(res.params, z_pad)
-                jax.block_until_ready(imgs)  # honest compute stamp
-                if fault == "nan":
-                    imgs = jnp.full_like(imgs, jnp.nan)
-                if self.nan_guard and not bool(jnp.all(jnp.isfinite(imgs))):
-                    res.nan_trips += 1
-                    raise GanServeError(
-                        f"arch {arch}: non-finite values in generated batch",
-                        arch=arch, kind="nan", attempts=attempt + 1,
+                with obs.span("gan.serve.assemble", **tags):
+                    z_all = jnp.concatenate([r.z for r in pending], axis=0)
+                    z_pad = jnp.pad(
+                        z_all, ((0, k - b),) + ((0, 0),) * (z_all.ndim - 1)
                     )
+                with obs.span("gan.serve.generate", **tags):
+                    imgs = res._generate(res.params, z_pad)
+                    jax.block_until_ready(imgs)  # honest compute stamp
+                    if fault == "nan":
+                        imgs = jnp.full_like(imgs, jnp.nan)
+                    if self.nan_guard and not bool(jnp.all(jnp.isfinite(imgs))):
+                        res.nan_trips += 1
+                        raise GanServeError(
+                            f"arch {arch}: non-finite values in generated batch",
+                            arch=arch, kind="nan", attempts=attempt + 1,
+                        )
             except Exception as e:  # isolation boundary — nothing escapes
                 retry_ok = attempt < self.max_retries
                 backoff_ms = min(
@@ -687,14 +694,15 @@ class GanServeEngine:
             res.bucket_counts[k] = res.bucket_counts.get(k, 0) + 1
             res.served += b
             self.served += b
-            t_done = _now_ms(now)
-            row = 0
-            for r in pending:
-                r.out = imgs[row : row + r.size]
-                row += r.size
-                r.t_done = t_done
-                r.done = True
-                r.event.set()
+            with obs.span("gan.serve.complete", **tags):
+                t_done = _now_ms(now)
+                row = 0
+                for r in pending:
+                    r.out = imgs[row : row + r.size]
+                    row += r.size
+                    r.t_done = t_done
+                    r.done = True
+                    r.event.set()
             res.breaker.on_success()
             return
 

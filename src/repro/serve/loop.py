@@ -38,6 +38,7 @@ from typing import Optional
 
 import jax
 
+from repro import obs
 from repro.serve.engine import GanFuture, GanRequest, GanServeEngine, _now_ms
 from repro.serve.faults import GanServeError
 
@@ -250,7 +251,8 @@ class AsyncGanServer:
                 continue
             if self._stop.is_set() and (not self._draining or self._idle()):
                 return
-            time.sleep(self.poll_interval_s)
+            with obs.span("gan.serve.poll"):
+                time.sleep(self.poll_interval_s)
 
     # ------------------------------------------------------------- watchdog
     def _on_worker_death(self, name: str) -> None:

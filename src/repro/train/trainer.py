@@ -19,7 +19,11 @@ Fault-tolerance contract (see ``train/resilience.py`` for the pieces):
     ``"preempted": True`` — resume is bit-exact vs an uninterrupted run;
   * async dispatch: with the sentinel off the loop never blocks on
     metrics except at log boundaries; with it on (the default) it reads
-    five device scalars per step — one small transfer.
+    five device scalars per step — one small transfer;
+  * inside a profiler session ``train_gan`` marks its host work with
+    ``repro.obs`` spans: ``gan.train.data`` (the batch), ``gan.train.step``
+    (the step's dispatch, and any retrace) and ``gan.train.sync`` (the
+    metrics fetch, where the loop waits for the device).
 """
 from __future__ import annotations
 
@@ -34,6 +38,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import data as D
+from repro import obs
 from repro.configs.base import GANConfig
 from repro.models import gan as G
 from repro.optim import adamw_init, adamw_update
@@ -543,22 +548,24 @@ def train_gan(
                     R.corrupt_latest_checkpoint(ckpt_dir)
                 if "raise" in inj:
                     raise R.InjectedTrainFault(f"injected raise at step {s}")
-                z = D.latent_batch(seed, s, batch, cfg.z_dim) if cfg.z_dim else D.gan_batch(
-                    seed, 1_000_000 + s, batch, cfg.img_hw
-                )
-                real = D.gan_batch(seed, s, batch, cfg.img_hw)
+                with obs.span("gan.train.data", step=s):
+                    z = D.latent_batch(seed, s, batch, cfg.z_dim) if cfg.z_dim else D.gan_batch(
+                        seed, 1_000_000 + s, batch, cfg.img_hw
+                    )
+                    real = D.gan_batch(seed, s, batch, cfg.img_hw)
                 if "nan_grad" in inj:
                     # NaN in the batch -> NaN losses/grads -> NaN update:
                     # the same poisoning a broken kernel or fp overflow does
                     z = z * jnp.float32(np.nan)
                 if skip_mode:
                     prev = (gp, dp, g_opt, d_opt, comm)
-                if comm is not None:
-                    gp, dp, g_opt, d_opt, comm, m = step_fn(
-                        gp, dp, g_opt, d_opt, comm, z, real
-                    )
-                else:
-                    gp, dp, g_opt, d_opt, m = step_fn(gp, dp, g_opt, d_opt, z, real)
+                with obs.span("gan.train.step", step=s):
+                    if comm is not None:
+                        gp, dp, g_opt, d_opt, comm, m = step_fn(
+                            gp, dp, g_opt, d_opt, comm, z, real
+                        )
+                    else:
+                        gp, dp, g_opt, d_opt, m = step_fn(gp, dp, g_opt, d_opt, z, real)
                 if hooks.step_deadline_s and time.monotonic() - t0 > hooks.step_deadline_s:
                     raise TimeoutError(f"step {s} exceeded deadline (straggler)")
             except (RuntimeError, TimeoutError) as e:
@@ -573,7 +580,8 @@ def train_gan(
                 continue
             host_m = None
             if detector is not None:
-                host_m = {k2: float(v) for k2, v in m.items()}
+                with obs.span("gan.train.sync", step=s):
+                    host_m = {k2: float(v) for k2, v in m.items()}
                 verdict = detector.observe(s, host_m)
                 if verdict is not None:
                     counters["sentinel_trips"] += 1
@@ -615,8 +623,10 @@ def train_gan(
                                      injected="nan_grad" in inj)
                     continue
             if (s + 1) % log_every == 0 or s + 1 == steps:
-                hm = host_m if host_m is not None else \
-                    {k2: float(v) for k2, v in m.items()}
+                hm = host_m
+                if hm is None:
+                    with obs.span("gan.train.sync", step=s):
+                        hm = {k2: float(v) for k2, v in m.items()}
                 _append_metrics({"step": s + 1, **hm})
                 if hooks.on_step:
                     hooks.on_step(s + 1, hm)

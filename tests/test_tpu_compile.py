@@ -14,6 +14,7 @@ describe the topology.  Keep every such test in this file.
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +96,26 @@ def test_conv_engine_compiles(one_chip, layer):
     n, m = chans[layer], chans[layer + 1]
     op = lambda x, w: ops.winograd_conv2d(x, w, cdims)
     _fwd_and_grad(op, (B, hw, hw, n), (G.DISC_KERNEL, G.DISC_KERNEL, n, m), one_chip)
+
+
+def test_kernels_are_named_by_layer(one_chip):
+    """Each engine kernel's HLO instruction, the name a profiler trace gives
+    its device op, reads ``<kind>_k<K>s<S>_<N>to<M>_<pass>``."""
+    dims, cdims = DCGAN.deconvs[3].dims, G.disc_conv_dims(DCGAN)[0]
+    cases = [
+        (lambda x, w: ops.winograd_deconv2d_fused(x, w, dims, fuse_pre=True),
+         (8, 32, 32, 128), (dims.kernel, dims.kernel, 128, 3), "deconv_k5s2_128to3"),
+        (lambda x, w: ops.winograd_conv2d(x, w, cdims),
+         (8, 64, 64, 3), (G.DISC_KERNEL, G.DISC_KERNEL, 3, 64), "conv_k4s2_3to64"),
+    ]
+    for op, x, w, tag in cases:
+        xs = jax.ShapeDtypeStruct(x, jnp.float32, sharding=one_chip)
+        ws = jax.ShapeDtypeStruct(w, jnp.float32, sharding=one_chip)
+        grad = jax.grad(lambda x, w: jnp.sum(op(x, w) ** 2), argnums=(0, 1))
+        text = _compile(grad, xs, ws).as_text()
+        names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+            r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"', text)}
+        assert names == {f"{tag}_fwd", f"{tag}_bwd_x", f"{tag}_bwd_w"}, names
 
 
 def test_conv1d_engine_compiles(one_chip):
