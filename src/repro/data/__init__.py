@@ -1,1 +1,1 @@
-from .synthetic import gan_batch, lm_batch, latent_batch, embed_batch
+from .synthetic import gan_batch, gan_inputs, lm_batch, latent_batch, embed_batch

@@ -21,7 +21,8 @@ Fault-tolerance contract (see ``train/resilience.py`` for the pieces):
     metrics except at log boundaries; with it on (the default) it reads
     five device scalars per step — one small transfer;
   * inside a profiler session ``train_gan`` marks its host work with
-    ``repro.obs`` spans: ``gan.train.data`` (the batch), ``gan.train.step``
+    ``repro.obs`` spans: ``gan.train.data`` (the batch, one compiled
+    ``data.gan_inputs`` dispatch), ``gan.train.step``
     (the step's dispatch, and any retrace) and ``gan.train.sync`` (the
     metrics fetch, where the loop waits for the device).
 """
@@ -360,8 +361,8 @@ def train_gan(
             RuntimeWarning, stacklevel=2,
         )
 
-    k = jax.random.PRNGKey(seed)
-    kg, kd = jax.random.split(k)
+    data_key = jax.random.PRNGKey(seed)  # D.gan_inputs draws each step's batch from it
+    kg, kd = jax.random.split(data_key)
     gp = G.generator_init(kg, cfg, dtype)
     dp = G.discriminator_init(kd, cfg, dtype)
     g_opt, d_opt = adamw_init(gp), adamw_init(dp)
@@ -549,10 +550,8 @@ def train_gan(
                 if "raise" in inj:
                     raise R.InjectedTrainFault(f"injected raise at step {s}")
                 with obs.span("gan.train.data", step=s):
-                    z = D.latent_batch(seed, s, batch, cfg.z_dim) if cfg.z_dim else D.gan_batch(
-                        seed, 1_000_000 + s, batch, cfg.img_hw
-                    )
-                    real = D.gan_batch(seed, s, batch, cfg.img_hw)
+                    z, real = D.gan_inputs(data_key, s, batch=batch, z_dim=cfg.z_dim,
+                                           hw=cfg.img_hw)
                 if "nan_grad" in inj:
                     # NaN in the batch -> NaN losses/grads -> NaN update:
                     # the same poisoning a broken kernel or fp overflow does

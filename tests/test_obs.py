@@ -146,6 +146,21 @@ def test_train_gan_opens_data_step_and_sync_once_a_step(tmp_path):
     assert obs.snapshot()["compiles"]["gan.train.step"]["count"] >= 1
 
 
+def test_train_gan_data_compiles_once_across_seeds_and_steps(tmp_path):
+    """A second run at the same shapes, with another seed and steps the first
+    never drew, compiles nothing under ``gan.train.data``: the batch is one
+    program whose key and step are arguments (``compiles.train`` stays 0)."""
+    from repro.train.trainer import train_gan
+
+    kw = dict(batch=2, log_every=1, handle_signals=False)
+    train_gan(tiny_dcgan("ref"), steps=1, seed=0, **kw)
+    with jax.profiler.trace(str(tmp_path)):
+        train_gan(tiny_dcgan("ref"), steps=3, seed=2**31 + 5, **kw)
+    snap = obs.snapshot()
+    assert snap["spans"]["gan.train.data"]["count"] == 3
+    assert "gan.train.data" not in snap["compiles"], snap["compiles"]
+
+
 def test_serve_dispatch_spans_carry_their_dispatch_index(tmp_path):
     from repro.serve import AsyncGanServer, GanServeEngine
 
